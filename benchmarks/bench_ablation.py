@@ -22,6 +22,7 @@ from repro.distributed.trainer import build_train_step, init_train_state, rank_f
 from repro.models import build_model
 from repro.optim import adamw
 from repro.configs import get_config
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def sketch_dim_ablation() -> None:
@@ -73,4 +74,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -11,6 +11,7 @@ from benchmarks.common import emit, time_fn
 from repro.core.aggregators import get_aggregator
 from repro.core.byzantine_sgd import ByzantineGuard, GuardConfig
 from repro.kernels import ops
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main() -> None:
@@ -53,4 +54,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

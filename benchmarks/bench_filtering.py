@@ -16,13 +16,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from benchmarks.common import emit, time_fn, write_json
+from benchmarks.common import chip_peaks, emit, time_fn, write_json
 from repro.core.byzantine_sgd import ByzantineGuard, GuardConfig
 from repro.core.solver import SolverConfig, run_sgd
 from repro.data.problems import make_generated_problem, make_quadratic_problem
 from repro.kernels import gradgen, ops, ref
 from repro.roofline.guard_cost import backend_cost, stats_elem_bytes
 from repro.roofline.guard_cost import steady_state_us
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def bench_detection_latency() -> None:
@@ -60,6 +61,7 @@ def bench_guard_pipeline(m: int = 32, d: int = 1 << 20, iters: int = 5,
     """
     if d_block is None:
         d_block = (1 << 16) if ops.interpret_mode() else 2048
+    hw = chip_peaks()
     # V matched to the i.i.d.-normal worker data (‖g_i − g_j‖ ≈ √(2d)): the
     # filter keeps honest workers, so the recorded good_k / ξ agreement
     # compares *live* decisions rather than the everyone-filtered
@@ -176,12 +178,12 @@ def bench_guard_pipeline(m: int = 32, d: int = 1 << 20, iters: int = 5,
             },
             "wallclock_us": {"dense": t_dense, "fused": t_fused,
                              "gen": t_gen},
-            # measured / bandwidth-modeled ratio of the gen step — the
-            # measured-vs-modeled band; only a roofline statement on TPU
-            # (on CPU the fused paths run the Pallas interpreter, see
-            # fused_runs_interpret)
-            "gen_measured_over_model": t_gen / max(
-                steady_state_us(cg), 1e-12),
+            # measured / bandwidth-modeled ratio of the gen step on the
+            # chip it ran on — the measured-vs-modeled band; None off the
+            # TPU, where there is no chip to model
+            "gen_measured_over_model": (
+                t_gen / max(steady_state_us(cg, hw), 1e-12)
+                if hw is not None else None),
             "agreement": {"gram_B_rel_err": gb_err,
                           "xi_max_abs_err": xi_err,
                           "good_k_equal": good_eq,
@@ -246,6 +248,7 @@ def main(m: int = 32, d: int = 1 << 20, iters: int = 5,
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--m", type=int, default=32)
     ap.add_argument("--d", type=int, default=1 << 20)

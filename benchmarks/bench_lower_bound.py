@@ -10,6 +10,7 @@ from repro.core.lower_bound import (
     distinguishing_experiment_linear,
     distinguishing_experiment_strongly_convex,
 )
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main() -> None:
@@ -28,4 +29,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -7,6 +7,7 @@ import json
 import os
 
 from benchmarks.common import emit
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main() -> None:
@@ -33,4 +34,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -57,7 +57,7 @@ import time
 
 import jax
 
-from benchmarks.common import emit
+from benchmarks.common import chip_peaks, emit
 from repro.core.guard_backends import parse_backend_spec
 from repro.core.solver import SolverConfig
 from repro.data.problems import (
@@ -68,7 +68,6 @@ from repro.data.problems import (
 from repro.kernels import ops
 from repro.obs import EventLog, TelemetryConfig, roofline_rows
 from repro.roofline.guard_cost import backend_cost, steady_state_us
-from repro.roofline.hw import TPU_V5E
 from repro.scenarios import (
     degraded_pairs,
     expand_grid,
@@ -88,6 +87,7 @@ from repro.scenarios import (
 )
 from repro.scenarios.campaign import CampaignResult, build_campaign_fn
 from repro.scenarios.report import campaign_trace_events, filter_timelines
+from repro.launch.compile_cache import enable_compile_cache
 
 # the blades-comparable aggregator cross: the classical zoo, the stateful
 # rules (AutoGM's auto-weighted geometric median, Karimireddy's
@@ -353,7 +353,8 @@ def backend_axis_record(prob, cfg, grid, backends: list[str]) -> dict:
     """Per-backend record: measured steady-state campaign wall-clock (each
     backend's guard-only campaign, compiled separately so the execution time
     is attributable) + the roofline-model per-step steady-state wall-clock
-    at the m = 32, d = 2²⁰ headline shape on the target TPU.
+    at the m = 32, d = 2²⁰ headline shape on the chip the run is on (no
+    modelled time off the TPU).
 
     On CPU the fused backend runs the Pallas *interpreter*, so its measured
     numbers are not comparable across backends (``interpret`` is recorded);
@@ -362,6 +363,7 @@ def backend_axis_record(prob, cfg, grid, backends: list[str]) -> dict:
     sweep is strictly cheaper than the dense 6-pass reference.
     """
     ms, ds = MODEL_SHAPE["m"], MODEL_SHAPE["d"]
+    hw = chip_peaks()
     per_backend = {}
     for be in backends:
         timed = run_campaign(prob, cfg, grid, ["byzantine_sgd"],
@@ -375,25 +377,31 @@ def backend_axis_record(prob, cfg, grid, backends: list[str]) -> dict:
             "stats_dtype": sdt or "f32",
             "model_stats_bytes": cost.stats_bytes,
             "model_step_bytes": cost.step_bytes,
-            "model_steady_state_us": steady_state_us(cost),
+            "model_steady_state_us": (steady_state_us(cost, hw)
+                                      if hw is not None else None),
         }
+        model_us = per_backend[be]["model_steady_state_us"]
         emit(f"scenarios/backend/{be}", timed.wall_s * 1e6,
              f"runs={timed.n_runs},"
-             f"model_step_us_m{ms}_d2e20={per_backend[be]['model_steady_state_us']:.0f}")
+             f"model_step_bytes_m{ms}_d2e20={cost.step_bytes}"
+             + (f",model_step_us_m{ms}_d2e20={model_us:.0f}"
+                if model_us is not None else ""))
     rec = {
         "backends": backends,
         "guard_opts": dict(cfg.guard_opts),
-        "model_shape": dict(MODEL_SHAPE, hw=TPU_V5E.name,
-                            hbm_bw=TPU_V5E.hbm_bw,
+        "model_shape": dict(MODEL_SHAPE,
+                            hw=hw.name if hw is not None else None,
+                            hbm_bw=hw.hbm_bw if hw is not None else None,
                             source="repro.roofline.guard_cost"),
         "measured_backend": jax.default_backend(),
         "fused_runs_interpret": ops.interpret_mode(),
         "per_backend": per_backend,
     }
     if "dense" in per_backend and "fused" in per_backend:
+        # bandwidth-bound model: the byte order is the time order
         rec["fused_le_dense_model"] = bool(
-            per_backend["fused"]["model_steady_state_us"]
-            <= per_backend["dense"]["model_steady_state_us"]
+            per_backend["fused"]["model_step_bytes"]
+            <= per_backend["dense"]["model_step_bytes"]
         )
     if "fused" in per_backend and "fused@bf16" in per_backend:
         # the ISSUE-5 headline: bf16 statistics move ≤ 0.55x the f32 bytes
@@ -483,8 +491,10 @@ def trace_campaign(mini: bool, trace_out: str,
             on, log, select=lambda e: e["scenario"] in dynamic)
         results_on[be] = on
     overhead = on_wall / max(off_wall, 1e-9) - 1.0
-    for row in roofline_rows(measured_step_us, m, d):
-        log.event("roofline", **row)
+    hw = chip_peaks()
+    if hw is not None:
+        for row in roofline_rows(measured_step_us, m, d, hw):
+            log.event("roofline", **row)
     timelines = [r for be in backends
                  for r in filter_timelines(results_on[be])]
     log.add_meta(telemetry_overhead_frac=overhead,
@@ -560,6 +570,7 @@ def main(mini: bool = False, skip_looped: bool = False,
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--mini", action="store_true",
                     help="CI tier-2 shape: 5 scenarios x 2 seeds, small T")

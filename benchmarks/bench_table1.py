@@ -18,6 +18,7 @@ from benchmarks.common import emit
 from repro.core.solver import SolverConfig
 from repro.data.problems import make_quadratic_problem
 from repro.scenarios import expand_grid, run_campaign, scenario_static
+from repro.launch.compile_cache import enable_compile_cache
 
 SEEDS = range(5)
 
@@ -101,4 +102,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

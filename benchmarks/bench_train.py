@@ -48,6 +48,7 @@ from repro.scenarios import (
     scenario_static,
     summarize_train_campaign,
 )
+from repro.launch.compile_cache import enable_compile_cache
 
 ARCH = "mamba2-130m"
 
@@ -260,6 +261,7 @@ def main(mini: bool = False, out_path: str = "BENCH_train.json",
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--mini", action="store_true",
                     help="CI tier-2 shape: 1 scenario x 2 seeds x 2 backends")

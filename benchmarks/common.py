@@ -7,6 +7,7 @@ import time
 import jax
 
 from repro.obs.provenance import provenance_meta
+from repro.roofline.hw import HwSpec, peaks_for
 
 
 def time_fn(fn, *args, warmup: int = 2, iters: int = 10) -> float:
@@ -20,6 +21,13 @@ def time_fn(fn, *args, warmup: int = 2, iters: int = 10) -> float:
         times.append(time.perf_counter() - t0)
     times.sort()
     return times[len(times) // 2] * 1e6
+
+
+def chip_peaks() -> HwSpec | None:
+    """Peak row of the chip this run is on; None off the TPU, where no
+    device time is modelled (a CPU run states bytes only)."""
+    dev = jax.devices()[0]
+    return peaks_for(dev.device_kind) if dev.platform == "tpu" else None
 
 
 def emit(name: str, us: float, derived: str = "") -> None:

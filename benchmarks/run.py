@@ -13,6 +13,8 @@ Prints ``name,us_per_call,derived`` CSV.  Select suites with
 """
 import sys
 
+from repro.launch.compile_cache import enable_compile_cache
+
 
 SUITES = ["table1", "aggregators", "filtering", "lower_bound", "ablation",
           "scenarios", "train", "roofline"]
@@ -29,4 +31,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -25,6 +25,9 @@ value-corruption cases):
                     every leaderboard gap finite, victims filtered
 ==================  =======================================================
 
+It is a CPU harness: the trainer children run with ``JAX_PLATFORMS=cpu``
+whatever the parent's environment says.
+
 Bit-parity is the strong form of the resume-equals-uninterrupted contract:
 the comparison is over the raw stored arrays of the final checkpoint, not a
 float tolerance.
@@ -63,9 +66,12 @@ def _train_cmd(ckpt_dir: str, steps: int, d_model: int, *extra: str) -> list[str
 
 
 def _env() -> dict:
+    # a CPU harness: the trainer children always run on the CPU.  On a
+    # machine with a chip this parent may hold it (the nonfinite case runs
+    # in-process), and a child that also asked for it would fail or hang
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(REPO, "src")
-    env.setdefault("JAX_PLATFORMS", "cpu")
+    env["JAX_PLATFORMS"] = "cpu"
     return env
 
 

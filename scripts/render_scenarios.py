@@ -23,6 +23,11 @@ def _fmt_gap(row: dict) -> str:
 MAX_TABLE_ROWS = 40
 
 
+def _us(v) -> str:
+    """Modelled µs, or a dash where the run had no chip with a peak row."""
+    return "—" if v is None else f"{v:.0f}"
+
+
 def _cap(rows: list, what: str) -> tuple[list, str | None]:
     """First ``MAX_TABLE_ROWS`` rows + a footer naming how many were cut."""
     if len(rows) <= MAX_TABLE_ROWS:
@@ -196,7 +201,7 @@ def render(rec: dict) -> str:
         lines.append(
             f"measured on `{ba['measured_backend']}` "
             f"(fused via Pallas interpreter: {ba['fused_runs_interpret']}); "
-            f"model = bytes/HBM-bandwidth on {shape['hw']} at "
+            f"model = bytes/HBM-bandwidth on {shape['hw'] or 'no chip'} at "
             f"m={shape['m']}, d={shape['d']}.\n"
         )
         lines.append("| backend | campaign wall s | runs | model step bytes "
@@ -206,7 +211,7 @@ def render(rec: dict) -> str:
             lines.append(
                 f"| {be} | {p['campaign_wall_s']:.2f} | {p['campaign_runs']} "
                 f"| {p['model_step_bytes']:,} "
-                f"| {p['model_steady_state_us']:.0f} |"
+                f"| {_us(p['model_steady_state_us'])} |"
             )
         if "fused_le_dense_model" in ba:
             lines.append(

@@ -16,10 +16,37 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.configs.base import InputShape
-from repro.distributed.sharding import logical_to_spec, use_logical_rules, param_pspecs
+from repro.distributed.sharding import (
+    LOGICAL_RULES_MULTI_POD,
+    LOGICAL_RULES_SINGLE_POD,
+    logical_to_spec,
+    param_pspecs,
+    use_logical_rules,
+)
 from repro.models.model import LanguageModel
 
 PyTree = Any
+
+
+def rules_for(shape: InputShape, multi_pod: bool, mesh: Mesh) -> dict:
+    """Logical→mesh rules of a production step at ``shape`` — shared by
+    the AOT dry-run and the four-chip run of ``chip_smoke.py``."""
+    rules = dict(LOGICAL_RULES_MULTI_POD if multi_pod else LOGICAL_RULES_SINGLE_POD)
+    # FSDP: shard the model-embed weight dim over the data axis (params are
+    # otherwise replicated across workers — fatal at 76B+). Activations use
+    # 'act_embed', so this touches weights only.
+    rules["embed"] = "data"
+    if shape.kind == "train":
+        # inside the per-worker vmap the activation batch dim is the
+        # *per-worker* batch; the worker axis already owns 'data' — sharding
+        # both produces conflicting group shardings (XLA SPMD CHECK failure)
+        rules["batch"] = None
+    if shape.is_decode and shape.global_batch < mesh.shape.get("data", 1):
+        # single-request long-context decode: batch can't use the data axis —
+        # give it to the KV-cache sequence dim instead (flash-decoding style)
+        rules["batch"] = None
+        rules["cache_seq"] = ("data", "model")
+    return rules
 
 
 def _ns(mesh: Mesh, spec: P) -> NamedSharding:

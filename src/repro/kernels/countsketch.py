@@ -26,7 +26,7 @@ def _sign_hash(idx: jax.Array, salt: int) -> jax.Array:
     h = h ^ (h >> 15)
     h = h * jnp.uint32(0x85EBCA6B)
     h = h ^ (h >> 13)
-    return 1.0 - 2.0 * (h & 1).astype(jnp.float32)
+    return 1.0 - 2.0 * (h & 1).astype(jnp.int32).astype(jnp.float32)
 
 
 def _countsketch_kernel(x_ref, out_ref, *, k: int, d_block: int, salt: int):

@@ -43,6 +43,11 @@ from jax.experimental import pallas as pl
 
 from repro.kernels.gradgen import GEN_NPARAMS, gen_worker_rows
 
+# f32 contractions on the MXU: at the default precision the TPU rounds f32
+# operands to bf16 (about 1e-3 relative error in a Gram); bf16 strips,
+# upcast in VMEM, are exact either way
+_F32_DOT = jax.lax.Precision.HIGHEST
+
 
 def _fused_guard_kernel(g_ref, b_ref, delta_ref,
                         gram_g_ref, cross_ref, a_inc_ref, b_new_ref):
@@ -60,10 +65,12 @@ def _fused_guard_kernel(g_ref, b_ref, delta_ref,
 
     contract = (((1,), (1,)), ((), ()))
     gram_g_ref[...] += jax.lax.dot_general(
-        g, g, contract, preferred_element_type=jnp.float32
+        g, g, contract, precision=_F32_DOT,
+        preferred_element_type=jnp.float32
     )
     cross_ref[...] += jax.lax.dot_general(     # ⟨B_i, g_j⟩ — pre-update B
-        b, g, contract, preferred_element_type=jnp.float32
+        b, g, contract, precision=_F32_DOT,
+        preferred_element_type=jnp.float32
     )
     a_inc_ref[...] += jnp.sum(g * dlt[None, :], axis=1)
     # f32 add, rounded once on the store when the B strips are bf16
@@ -97,10 +104,12 @@ def _fused_guard_sanitize_kernel(g_ref, b_ref, delta_ref,
 
     contract = (((1,), (1,)), ((), ()))
     gram_g_ref[...] += jax.lax.dot_general(
-        g, g, contract, preferred_element_type=jnp.float32
+        g, g, contract, precision=_F32_DOT,
+        preferred_element_type=jnp.float32
     )
     cross_ref[...] += jax.lax.dot_general(
-        b, g, contract, preferred_element_type=jnp.float32
+        b, g, contract, precision=_F32_DOT,
+        preferred_element_type=jnp.float32
     )
     a_inc_ref[...] += jnp.sum(g * dlt[None, :], axis=1)
     # B accumulates the *sanitized* gradient: the martingale stays finite
@@ -243,10 +252,12 @@ def _fused_guard_gen_kernel(b_ref, delta_ref, x_ref, h_ref, xs_ref, hd_ref,
 
     contract = (((1,), (1,)), ((), ()))
     gram_g_ref[...] += jax.lax.dot_general(
-        g, g, contract, preferred_element_type=jnp.float32
+        g, g, contract, precision=_F32_DOT,
+        preferred_element_type=jnp.float32
     )
     cross_ref[...] += jax.lax.dot_general(
-        b, g, contract, preferred_element_type=jnp.float32
+        b, g, contract, precision=_F32_DOT,
+        preferred_element_type=jnp.float32
     )
     a_inc_ref[...] += jnp.sum(g * dlt[None, :], axis=1)
     b_new_ref[...] = (b + g).astype(b_new_ref.dtype)
@@ -340,7 +351,9 @@ def _gen_xi_kernel(wxi_ref, wbyz_ref, x_ref, h_ref, xs_ref, hd_ref,
     # raw f32 rows (what the host adversary.update_state sees)
     gs = rows.astype(stats_dtype).astype(jnp.float32)
     w = wxi_ref[...].astype(jnp.float32)
-    xi_ref[...] = jnp.einsum("m,md->d", w, gs)
+    # (1, m) @ (m, d_blk): Mosaic has no 1-D contraction
+    xi_ref[...] = jnp.dot(w[None, :], gs, precision=_F32_DOT,
+                          preferred_element_type=jnp.float32)[0]
     byz_ref[...] = jnp.sum(rows * wbyz_ref[...][:, None], axis=0)
 
 

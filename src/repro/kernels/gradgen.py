@@ -73,7 +73,9 @@ def threefry2x32(k0, k1, c0, c1):
 def centered_uniform(bits: jax.Array) -> jax.Array:
     """uint32 bits → f32 uniform in (−1, 1): the top 23 bits land on the
     open-interval lattice ((b >> 9) + 0.5)·2⁻²³ ∈ (0, 1), then center."""
-    u = ((bits >> 9).astype(jnp.float32) + 0.5) * jnp.float32(2.0 ** -23)
+    # b >> 9 < 2^23 is exact through int32; Mosaic has no uint32 → f32 cast
+    top = (bits >> 9).astype(jnp.int32).astype(jnp.float32)
+    u = (top + 0.5) * jnp.float32(2.0 ** -23)
     return 2.0 * u - 1.0
 
 
@@ -198,17 +200,20 @@ def gen_worker_rows(x, h, x_star, het_dir, keys, skewsign, slot, params, j, d):
     """
     p = params
     jm = j.reshape(1, -1)
+    # per-worker vectors become (mp, 1) columns before any comparison:
+    # Mosaic cannot reshape a boolean vector into a column
+    slot = slot[:, None]
+    skewsign = skewsign[:, None]
     t = mean_grad(h, x, x_star)                              # true-grad strip
     bits = threefry2x32(keys[:, 0][:, None], keys[:, 1][:, None],
                         jnp.zeros_like(jm), jm)[0]           # (mp, blk)
     g = t[None, :] + p[P_NSCALE] * centered_uniform(bits)
-    g = jnp.where(skewsign[:, None] != 0.0,
-                  g + skewsign[:, None] * het_dir[None, :], g)
+    g = jnp.where(skewsign != 0.0, g + skewsign * het_dir[None, :], g)
 
     # honest strip moments — the expressions of attacks._good_row_stats
     # (population moments over honest rows; coordinate-local, so the strip
     # slice equals the full-width computation)
-    w = (slot == 0).astype(jnp.float32)[:, None]
+    w = (slot == 0).astype(jnp.float32)
     n_good = jnp.maximum(jnp.sum(w), 1.0)
     mu = jnp.sum(g * w, axis=0) / n_good
     var = jnp.sum(w * (g - mu[None, :]) ** 2, axis=0) / n_good
@@ -226,16 +231,13 @@ def gen_worker_rows(x, h, x_star, het_dir, keys, skewsign, slot, params, j, d):
     # every branch is a cheap affine row — ids 0/2 (none / the unsupported
     # random_gaussian) fall through to the honest row
     row = g
-    row = jnp.where((aid == 1.0)[:, None], sf[:, None] * g, row)
-    row = jnp.where((aid == 3.0)[:, None], cst[:, None] + jnp.zeros_like(g), row)
-    row = jnp.where((aid == 4.0)[:, None],
-                    mu[None, :] - zf[:, None] * sig[None, :], row)
-    row = jnp.where((aid == 8.0)[:, None],
-                    mu[None, :] + zf[:, None] * sig[None, :], row)
-    row = jnp.where((aid == 5.0)[:, None],
-                    t[None, :] - ipc[:, None] * gn[None, :], row)
-    row = jnp.where((aid == 6.0)[:, None], t[None, :] + cst[:, None], row)
-    out = jnp.where((slot > 0)[:, None], row, g)
+    row = jnp.where(aid == 1.0, sf * g, row)
+    row = jnp.where(aid == 3.0, cst + jnp.zeros_like(g), row)
+    row = jnp.where(aid == 4.0, mu[None, :] - zf * sig[None, :], row)
+    row = jnp.where(aid == 8.0, mu[None, :] + zf * sig[None, :], row)
+    row = jnp.where(aid == 5.0, t[None, :] - ipc * gn[None, :], row)
+    row = jnp.where(aid == 6.0, t[None, :] + cst, row)
+    out = jnp.where(slot > 0, row, g)
 
-    keep = (slot >= 0)[:, None] & (jm < jnp.uint32(d))
+    keep = (slot >= 0) & (jm < jnp.uint32(d))
     return jnp.where(keep, out, 0.0)

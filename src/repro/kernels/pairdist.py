@@ -15,6 +15,11 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+# f32 contractions on the MXU: at the default precision the TPU rounds f32
+# operands to bf16 (about 1e-3 relative error in a Gram); bf16 strips,
+# upcast in VMEM, are exact either way
+_F32_DOT = jax.lax.Precision.HIGHEST
+
 
 def _gram_kernel(x_ref, out_ref):
     i = pl.program_id(0)
@@ -25,7 +30,8 @@ def _gram_kernel(x_ref, out_ref):
 
     x = x_ref[...].astype(jnp.float32)
     out_ref[...] += jax.lax.dot_general(
-        x, x, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        x, x, (((1,), (1,)), ((), ())), precision=_F32_DOT,
+        preferred_element_type=jnp.float32
     )
 
 

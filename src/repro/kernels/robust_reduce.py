@@ -21,6 +21,11 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+# f32 contractions on the MXU: at the default precision the TPU rounds f32
+# operands to bf16 (about 1e-3 relative error in a Gram); bf16 strips,
+# upcast in VMEM, are exact either way
+_F32_DOT = jax.lax.Precision.HIGHEST
+
 
 def _sorted_over_workers(x: jax.Array) -> jax.Array:
     """Bitonic-style full sort over axis 0 (m is small and static): odd-even
@@ -63,7 +68,9 @@ def _filtered_mean_kernel(x_ref, mask_ref, out_ref, *, denom: float,
         # before the reduction; off-state kernel body is unchanged
         x = jnp.where(jnp.isfinite(x), x, 0.0)
     w = mask_ref[...].astype(jnp.float32) / denom
-    out_ref[...] = jnp.einsum("m,md->d", w, x)
+    # (1, m) @ (m, d_blk): Mosaic has no 1-D contraction
+    out_ref[...] = jnp.dot(w[None, :], x, precision=_F32_DOT,
+                           preferred_element_type=jnp.float32)[0]
 
 
 def _reduce_call(kernel, x, extra_inputs=(), extra_specs=(), d_block=4096,

@@ -33,12 +33,13 @@ import jax.numpy as jnp
 from repro.configs import ARCH_IDS, INPUT_SHAPES, get_config
 from repro.configs.base import InputShape, ModelConfig
 from repro.core.solver import SolverConfig
-from repro.distributed.sharding import (
-    LOGICAL_RULES_MULTI_POD,
-    LOGICAL_RULES_SINGLE_POD,
-    use_logical_rules,
+from repro.distributed.sharding import use_logical_rules
+from repro.distributed.specs import (
+    make_prefill_specs,
+    make_serve_specs,
+    make_train_specs,
+    rules_for,
 )
-from repro.distributed.specs import make_prefill_specs, make_serve_specs, make_train_specs
 from repro.distributed.trainer import build_serve_step, build_train_step, init_train_state
 from repro.launch.mesh import make_production_mesh, n_workers
 from repro.models import build_model
@@ -63,25 +64,6 @@ def arch_variant_for_shape(cfg: ModelConfig, shape: InputShape) -> tuple[ModelCo
     if cfg.sliding_window:
         return cfg, "native-swa"            # starcoder2: already windowed
     return dataclasses.replace(cfg, sliding_window=LONG_CONTEXT_WINDOW), "swa-variant"
-
-
-def rules_for(shape: InputShape, multi_pod: bool, mesh) -> dict:
-    rules = dict(LOGICAL_RULES_MULTI_POD if multi_pod else LOGICAL_RULES_SINGLE_POD)
-    # FSDP: shard the model-embed weight dim over the data axis (params are
-    # otherwise replicated across workers — fatal at 76B+). Activations use
-    # 'act_embed', so this touches weights only.
-    rules["embed"] = "data"
-    if shape.kind == "train":
-        # inside the per-worker vmap the activation batch dim is the
-        # *per-worker* batch; the worker axis already owns 'data' — sharding
-        # both produces conflicting group shardings (XLA SPMD CHECK failure)
-        rules["batch"] = None
-    if shape.is_decode and shape.global_batch < mesh.shape.get("data", 1):
-        # single-request long-context decode: batch can't use the data axis —
-        # give it to the KV-cache sequence dim instead (flash-decoding style)
-        rules["batch"] = None
-        rules["cache_seq"] = ("data", "model")
-    return rules
 
 
 def _kind(shape: InputShape) -> str:

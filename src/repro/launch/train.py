@@ -50,6 +50,7 @@ Examples:
 from __future__ import annotations
 
 import argparse
+import functools
 import json
 import os
 import signal
@@ -63,6 +64,7 @@ from repro.configs import get_config
 from repro.core.solver import SolverConfig, byz_rank
 from repro.data.synthetic import SyntheticTokens, make_worker_batch
 from repro.distributed.trainer import build_train_step, init_train_state
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build_model
 from repro.obs import EventLog, TelemetryConfig, trace_span
 from repro.optim import adamw, linear_warmup_cosine
@@ -233,10 +235,13 @@ def run_training(
             save_checkpoint(ckpt_dir, int(jax.device_get(state.step)), state,
                             keep_last=keep_last)
 
-    def flush_recs(ms, lo, hi, stacked=True):
+    def flush_recs(ms, lo, hi, seg_s, stacked=True):
         """Host-side split of one metrics transfer: ``tel/`` forensics
         (per-worker arrays included) go to the event log as guard_step
-        events, everything else becomes scalar history records."""
+        events, everything else becomes scalar history records.
+        ``step_s`` is the host wall time of the segment the step ran in
+        (dispatch to metrics on the host, compilation included on the first
+        call of each program) over its step count."""
         for j, i in enumerate(range(lo, hi)):
             rec, frame = {}, {}
             for k, v in ms.items():
@@ -246,6 +251,7 @@ def run_training(
                 else:
                     rec[k] = float(vj)
             rec["step"] = i
+            rec["step_s"] = seg_s / (hi - lo)
             history.append(rec)
             if elog is not None and frame:
                 elog.guard_step(frame, run=run_label)
@@ -264,28 +270,34 @@ def run_training(
         # log_every chunks go through ONE scan program; ragged head/tail
         # segments (resume from an unaligned step, final remainder) run
         # through the shared per-step program instead of retracing the
-        # whole model scan at a new length
-        @jax.jit
+        # whole model scan at a new length.  The carried state is donated:
+        # the loop only rebinds `state` to the result, and without donation
+        # the old and new TrainState are live at once (a full-width
+        # mamba2-130m run at W=8 then no longer fits a 16 GiB chip)
+        @functools.partial(jax.jit, donate_argnums=0)
         def run_chunk(st, idx):
             def body(s, i):
                 s, m = one_step(s, i)
                 return s, m
             return jax.lax.scan(body, st, idx)
 
-        step_fn = jax.jit(one_step)
+        step_fn = jax.jit(one_step, donate_argnums=0)
 
         def run_segment(state, lo, hi):
             if hi - lo == log_every:
+                t_seg = time.perf_counter()
                 with trace_span("train/chunk", log=elog, lo=lo, hi=hi):
                     state, ms = run_chunk(state, jnp.arange(lo, hi))
                     ms = jax.device_get(ms)
-                flush_recs(ms, lo, hi)
+                flush_recs(ms, lo, hi, time.perf_counter() - t_seg)
             else:
                 for i in range(lo, hi):
+                    t_seg = time.perf_counter()
                     with trace_span("train/step", log=elog, i=i):
                         state, m = step_fn(state, jnp.asarray(i))
                         m = jax.device_get(m)
-                    flush_recs(m, i, i + 1, stacked=False)
+                    flush_recs(m, i, i + 1, time.perf_counter() - t_seg,
+                               stacked=False)
             return state
 
         lo = start
@@ -309,8 +321,10 @@ def run_training(
         for i in range(start, stop):
             if preempted["hit"]:
                 break
+            t_seg = time.perf_counter()
             state, metrics = step_fn(state, jnp.asarray(i))
-            flush_recs(jax.device_get(metrics), i, i + 1, stacked=False)
+            flush_recs(jax.device_get(metrics), i, i + 1,
+                       time.perf_counter() - t_seg, stacked=False)
             if i % log_every == 0 or i == stop - 1:
                 log(history[-1])
             maybe_ckpt(state, i + 1)
@@ -391,6 +405,7 @@ def main():
                          "write the structured JSONL event log here; render "
                          "with scripts/render_trace.py")
     args = ap.parse_args()
+    enable_compile_cache()
     run_training(
         args.arch, reduced=args.reduced, workers=args.workers,
         per_worker_batch=args.per_worker_batch, seq_len=args.seq_len,

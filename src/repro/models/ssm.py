@@ -89,8 +89,11 @@ def _ssd_scan(
     decay = cum[:, :, :, None, :] - cum[:, :, None, :, :]      # cum_i - cum_j (B,nc,Q,Q,H)
     decay = jnp.moveaxis(decay, -1, 2)                         # (B,nc,H,Q,Q)
     iq = jnp.arange(Q)
-    causal = (iq[:, None] >= iq[None, :])
-    M = jnp.where(causal[None, None, None], s * jnp.exp(decay), 0.0)
+    causal = (iq[:, None] >= iq[None, :])[None, None, None]
+    # mask *before* the exp: above the diagonal cum_i − cum_j > 0 grows with
+    # the chunk length and overflows f32 at Q = 256, and masking the
+    # product afterwards still sends 0·inf = NaN back through exp's gradient
+    M = s * jnp.exp(jnp.where(causal, decay, -jnp.inf))
     y_intra = jnp.einsum("bchij,bcjhp->bcihp", M, dtx)
 
     # ---- per-chunk outgoing state ----

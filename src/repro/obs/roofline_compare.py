@@ -16,9 +16,11 @@ is miscounting passes.
 from __future__ import annotations
 
 
-def roofline_rows(measured_step_us: dict[str, float], m: int, d: int) -> list[dict]:
+def roofline_rows(measured_step_us: dict[str, float], m: int, d: int,
+                  hw) -> list[dict]:
     """Join measured per-step µs (keyed by backend spec, ``@dtype``
-    suffixes honored) against the guard_cost prediction at (m, d)."""
+    suffixes honored) against the guard_cost prediction at (m, d) on the
+    chip ``hw`` the measurement ran on (a ``repro.roofline.hw`` row)."""
     # deferred: guard_backends itself imports repro.obs (the telemetry
     # probe), so a module-level import here would be circular
     from repro.core.guard_backends import parse_backend_spec
@@ -28,7 +30,7 @@ def roofline_rows(measured_step_us: dict[str, float], m: int, d: int) -> list[di
     for spec, meas in sorted(measured_step_us.items()):
         name, sdt = parse_backend_spec(spec)
         cost = backend_cost(name, m, d, sdt or "f32")
-        model = steady_state_us(cost)
+        model = steady_state_us(cost, hw)
         rows.append({
             "backend": spec,
             "m": m,

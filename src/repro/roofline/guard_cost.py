@@ -47,15 +47,15 @@ flat harness — DESIGN.md §9) follow the same pass-count accounting:
 
 ``BACKEND_COSTS`` maps every registered guard-backend name to its model,
 and :func:`steady_state_us` converts bytes to the bandwidth-bound
-steady-state wall-clock on the target hardware — the per-backend number
+steady-state wall-clock on a given chip's peak row — the per-backend number
 ``benchmarks/bench_scenarios.py`` records at the m = 32, d = 2²⁰ headline
-shape.
+shape when it runs on a chip with a row in :data:`repro.roofline.hw.PEAKS`.
 """
 from __future__ import annotations
 
 from typing import NamedTuple
 
-from repro.roofline.hw import TPU_V5E, HwSpec
+from repro.roofline.hw import HwSpec
 
 
 class GuardStepCost(NamedTuple):
@@ -189,8 +189,9 @@ def backend_cost(backend: str, m: int, d: int,
     return BACKEND_COSTS[backend](m, d, elem_bytes=stats_elem_bytes(stats_dtype))
 
 
-def steady_state_us(cost: GuardStepCost, hw: HwSpec = TPU_V5E) -> float:
-    """Bandwidth-bound steady-state wall-clock of one guard step (µs): the
+def steady_state_us(cost: GuardStepCost, hw: HwSpec) -> float:
+    """Bandwidth-bound steady-state wall-clock of one guard step (µs) on
+    the chip ``hw`` (a :func:`repro.roofline.hw.peaks_for` row): the
     guard's arithmetic intensity sits far under the ridge point on every
     realistic shape, so bytes / HBM bandwidth *is* the wall-clock model."""
     return cost.step_bytes / hw.hbm_bw * 1e6

@@ -116,6 +116,27 @@ class TestSSD:
         np.testing.assert_allclose(jnp.concatenate([y1, y2], 1), y_full, rtol=2e-3, atol=2e-3)
         np.testing.assert_allclose(s2, s_full, rtol=2e-3, atol=2e-3)
 
+    def test_gradient_finite_at_long_chunk(self, rng):
+        """At a published chunk length (256) the decay between the ends of
+        a chunk overflows f32 above the diagonal; the gradient must stay
+        finite, and the forward must equal the same scan at a short chunk."""
+        B, S, H, P, G, N = 1, 256, 2, 4, 1, 8
+        ks = jax.random.split(rng, 5)
+        x = jax.random.normal(ks[0], (B, S, H, P))
+        dt = jnp.full((B, S, H), 0.5)
+        A = jnp.array([-1.0, -8.0])   # Σ A·dt over the chunk ≈ −1024 ≪ −88
+        Bm = jax.random.normal(ks[3], (B, S, G, N)) * 0.5
+        Cm = jax.random.normal(ks[4], (B, S, G, N)) * 0.5
+
+        def loss(x, dt, Bm, Cm, chunk):
+            return jnp.sum(_ssd_scan(x, dt, A, Bm, Cm, chunk=chunk)[0] ** 2)
+
+        grads = jax.grad(loss, argnums=(0, 1, 2, 3))(x, dt, Bm, Cm, 256)
+        for g in grads:
+            assert bool(jnp.all(jnp.isfinite(g)))
+        np.testing.assert_allclose(loss(x, dt, Bm, Cm, 256),
+                                   loss(x, dt, Bm, Cm, 32), rtol=2e-3)
+
 
 class TestMoE:
     def _cfg(self, **kw):
