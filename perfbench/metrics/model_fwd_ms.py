@@ -1,0 +1,8 @@
+"""Device time of the model's forward passes, in milliseconds per training
+step of the traced window: the ops under the ``model/loss`` scope whose
+name holds no ``transpose(`` (``perfbench/layers.py``)."""
+from perfbench import layers
+
+
+def read(record):
+    return layers.ms_per_step(record, "model_fwd")

@@ -344,119 +344,122 @@ def guard_step(
     k_new = state.k + 1
     lp = cfg.low_precision_stats
     sdt = resolve_stats_dtype(cfg.stats_dtype)
-    if sdt != jnp.dtype(jnp.float32):
-        # the single entry rounding of the stats axis (same convention as
-        # the dense/fused guards): every statistic below — A, both Grams,
-        # the cross term, B, ξ — consumes the *rounded* gradients, so the
-        # incremental Gram tracks the same martingale the bf16 B storage
-        # actually accumulates (a no-op when the trainer already ravelled
-        # to bf16; f32 flat-harness inputs are rounded here)
-        grads_w = jax.tree_util.tree_map(lambda g: g.astype(sdt), grads_w)
+    # guard/stats_sweep (DESIGN.md §12): the entry rounding, the report
+    # masking and the martingale block, under the dense/fused guards' name
+    with jax.named_scope("guard/stats_sweep"):
+        if sdt != jnp.dtype(jnp.float32):
+            # the single entry rounding of the stats axis (same convention as
+            # the dense/fused guards): every statistic below — A, both Grams,
+            # the cross term, B, ξ — consumes the *rounded* gradients, so the
+            # incremental Gram tracks the same martingale the bf16 B storage
+            # actually accumulates (a no-op when the trainer already ravelled
+            # to bf16; f32 flat-harness inputs are rounded here)
+            grads_w = jax.tree_util.tree_map(lambda g: g.astype(sdt), grads_w)
 
-    def _mask_workers(g: PyTree) -> PyTree:
-        # entry masking for partial participation (DESIGN.md §13): zero
-        # rows freeze A/B for non-reporters and keep the incremental-Gram
-        # identity exact, so every contraction below runs unchanged
-        return jax.tree_util.tree_map(
-            lambda x: jnp.where(
-                report.reshape((-1,) + (1,) * (x.ndim - 1)), x,
-                jnp.zeros((), x.dtype)),
-            g,
-        )
-
-    if report is not None:
-        grads_w = _mask_workers(grads_w)
-
-    # --- martingale updates -------------------------------------------------
-    if lp:
-        delta = jax.tree_util.tree_map(
-            lambda p, a: (p.astype(jnp.float32) - a.astype(jnp.float32)).astype(p.dtype),
-            params, anchor,
-        )
-    else:
-        delta = jax.tree_util.tree_map(
-            lambda p, a: _leaf_f32(p) - _leaf_f32(a), params, anchor
-        )
-    A = state.A + worker_vdot(grads_w, delta, lp)
-
-    sq_g = worker_sq_norms(grads_w, lp)
-    if cfg.mode == "sketch":
-        # Center before sketching: pairwise distances are invariant under a
-        # common shift, but sketch noise scales with the norms of what is
-        # sketched — ‖g_i − ḡ‖ (the deviation scale, what the filter
-        # measures) instead of ‖g_i‖ (huge and common-mode). One extra
-        # mean-reduce of the gradients, orders less than exact mode's
-        # all-gather.
-        if report is None:
-            n_mean = None
-        else:
-            # reporter-count mean: masked rows are already zero, so the sum
-            # runs over reporters — only the divisor changes
-            n_mean = jnp.maximum(jnp.sum(report), 1).astype(jnp.float32)
-        if lp:
-            g_mean = jax.tree_util.tree_map(
-                lambda g: (jnp.mean(g, axis=0, keepdims=True, dtype=jnp.float32)
-                           if n_mean is None else
-                           jnp.sum(g, axis=0, keepdims=True, dtype=jnp.float32)
-                           / n_mean).astype(g.dtype), grads_w
+        def _mask_workers(g: PyTree) -> PyTree:
+            # entry masking for partial participation (DESIGN.md §13): zero
+            # rows freeze A/B for non-reporters and keep the incremental-Gram
+            # identity exact, so every contraction below runs unchanged
+            return jax.tree_util.tree_map(
+                lambda x: jnp.where(
+                    report.reshape((-1,) + (1,) * (x.ndim - 1)), x,
+                    jnp.zeros((), x.dtype)),
+                g,
             )
-            g_cent = jax.tree_util.tree_map(lambda g, c: g - c, grads_w, g_mean)
-        else:
-            g_mean = jax.tree_util.tree_map(
-                lambda g: (jnp.mean(_leaf_f32(g), axis=0, keepdims=True)
-                           if n_mean is None else
-                           jnp.sum(_leaf_f32(g), axis=0, keepdims=True) / n_mean),
-                grads_w,
-            )
-            g_cent = jax.tree_util.tree_map(
-                lambda g, c: _leaf_f32(g) - c, grads_w, g_mean
-            )
+
         if report is not None:
-            # re-mask after centering: a non-reporter's centered row would
-            # be −ḡ (not 0) and leak into its frozen B sketch
-            g_cent = _mask_workers(g_cent)
-        sq_cent = worker_sq_norms(g_cent, lp)
-        s_g = sketch_tree(g_cent, cfg.sketch_dim, lp)
-        # (W, k) sketch state: stored in the stats dtype, accumulated and
-        # contracted in f32 (the sketch is tiny — the cast is free)
-        B = (state.B.astype(jnp.float32) + s_g).astype(sdt)
-        gram_g = sketch_gram(s_g, sq_cent)
-        B32 = B.astype(jnp.float32)
-        gram_B = sketch_gram(B32, jnp.sum(B32 * B32, axis=-1))
-    else:
+            grads_w = _mask_workers(grads_w)
+
+        # --- martingale updates -------------------------------------------------
         if lp:
-            # no param-sized f32 temporaries: native-dtype add, stored in
-            # the stats dtype (the one new rounding of the bf16 axis)
-            B = jax.tree_util.tree_map(
-                lambda b, g: (b + g.astype(b.dtype)).astype(sdt),
-                state.B, grads_w,
+            delta = jax.tree_util.tree_map(
+                lambda p, a: (p.astype(jnp.float32) - a.astype(jnp.float32)).astype(p.dtype),
+                params, anchor,
             )
         else:
-            B = jax.tree_util.tree_map(
-                lambda b, g: (_leaf_f32(b) + _leaf_f32(g)).astype(sdt),
-                state.B, grads_w,
+            delta = jax.tree_util.tree_map(
+                lambda p, a: _leaf_f32(p) - _leaf_f32(a), params, anchor
             )
-        gram_g = worker_cross_gram(grads_w, lp)
-        if cfg.incremental_gram:
-            def _incremental():
-                # G_B^k = G_B^{k-1} + B gᵀ + g Bᵀ + g gᵀ — no contraction
-                # over (and no all-gather of) the accumulated B pytree
-                cross = worker_pair_gram(state.B, grads_w, lp)
-                return state.gram_B + cross + cross.T + gram_g
+        A = state.A + worker_vdot(grads_w, delta, lp)
 
-            if cfg.gram_resync_every > 0:
-                # zero the accumulated rounding (bf16 cross terms under lp)
-                # with a from-scratch contraction every N-th step; both
-                # alternatives live inside the cond so only one is paid
-                gram_B = jax.lax.cond(
-                    k_new % cfg.gram_resync_every == 0,
-                    lambda: worker_cross_gram(B, lp),
-                    _incremental,
+        sq_g = worker_sq_norms(grads_w, lp)
+        if cfg.mode == "sketch":
+            # Center before sketching: pairwise distances are invariant under a
+            # common shift, but sketch noise scales with the norms of what is
+            # sketched — ‖g_i − ḡ‖ (the deviation scale, what the filter
+            # measures) instead of ‖g_i‖ (huge and common-mode). One extra
+            # mean-reduce of the gradients, orders less than exact mode's
+            # all-gather.
+            if report is None:
+                n_mean = None
+            else:
+                # reporter-count mean: masked rows are already zero, so the sum
+                # runs over reporters — only the divisor changes
+                n_mean = jnp.maximum(jnp.sum(report), 1).astype(jnp.float32)
+            if lp:
+                g_mean = jax.tree_util.tree_map(
+                    lambda g: (jnp.mean(g, axis=0, keepdims=True, dtype=jnp.float32)
+                               if n_mean is None else
+                               jnp.sum(g, axis=0, keepdims=True, dtype=jnp.float32)
+                               / n_mean).astype(g.dtype), grads_w
+                )
+                g_cent = jax.tree_util.tree_map(lambda g, c: g - c, grads_w, g_mean)
+            else:
+                g_mean = jax.tree_util.tree_map(
+                    lambda g: (jnp.mean(_leaf_f32(g), axis=0, keepdims=True)
+                               if n_mean is None else
+                               jnp.sum(_leaf_f32(g), axis=0, keepdims=True) / n_mean),
+                    grads_w,
+                )
+                g_cent = jax.tree_util.tree_map(
+                    lambda g, c: _leaf_f32(g) - c, grads_w, g_mean
+                )
+            if report is not None:
+                # re-mask after centering: a non-reporter's centered row would
+                # be −ḡ (not 0) and leak into its frozen B sketch
+                g_cent = _mask_workers(g_cent)
+            sq_cent = worker_sq_norms(g_cent, lp)
+            s_g = sketch_tree(g_cent, cfg.sketch_dim, lp)
+            # (W, k) sketch state: stored in the stats dtype, accumulated and
+            # contracted in f32 (the sketch is tiny — the cast is free)
+            B = (state.B.astype(jnp.float32) + s_g).astype(sdt)
+            gram_g = sketch_gram(s_g, sq_cent)
+            B32 = B.astype(jnp.float32)
+            gram_B = sketch_gram(B32, jnp.sum(B32 * B32, axis=-1))
+        else:
+            if lp:
+                # no param-sized f32 temporaries: native-dtype add, stored in
+                # the stats dtype (the one new rounding of the bf16 axis)
+                B = jax.tree_util.tree_map(
+                    lambda b, g: (b + g.astype(b.dtype)).astype(sdt),
+                    state.B, grads_w,
                 )
             else:
-                gram_B = _incremental()
-        else:
-            gram_B = worker_cross_gram(B, lp)
+                B = jax.tree_util.tree_map(
+                    lambda b, g: (_leaf_f32(b) + _leaf_f32(g)).astype(sdt),
+                    state.B, grads_w,
+                )
+            gram_g = worker_cross_gram(grads_w, lp)
+            if cfg.incremental_gram:
+                def _incremental():
+                    # G_B^k = G_B^{k-1} + B gᵀ + g Bᵀ + g gᵀ — no contraction
+                    # over (and no all-gather of) the accumulated B pytree
+                    cross = worker_pair_gram(state.B, grads_w, lp)
+                    return state.gram_B + cross + cross.T + gram_g
+
+                if cfg.gram_resync_every > 0:
+                    # zero the accumulated rounding (bf16 cross terms under lp)
+                    # with a from-scratch contraction every N-th step; both
+                    # alternatives live inside the cond so only one is paid
+                    gram_B = jax.lax.cond(
+                        k_new % cfg.gram_resync_every == 0,
+                        lambda: worker_cross_gram(B, lp),
+                        _incremental,
+                    )
+                else:
+                    gram_B = _incremental()
+            else:
+                gram_B = worker_cross_gram(B, lp)
 
     # --- V calibration + filter --------------------------------------------
     # guard/filter named scope (DESIGN.md §12 span convention): the dp

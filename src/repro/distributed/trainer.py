@@ -224,8 +224,10 @@ def build_train_step(
         attack_kwargs = dict(cfg.attack_kwargs)
 
     def loss_one(params, tb):
-        loss, metrics = model.loss_fn(params, tb)
-        return loss, metrics
+        # named scopes of the step's layers (repro.obs.STEP_SCOPES): HLO
+        # metadata only, read from a device trace by perfbench/layers.py
+        with jax.named_scope("model/loss"):
+            return model.loss_fn(params, tb)
 
     def train_step(state: TrainState, batch: dict, byz_rank: jax.Array,
                    key: jax.Array):
@@ -237,8 +239,9 @@ def build_train_step(
             return loss, g
 
         losses_w, grads_w = jax.vmap(per_worker)(batch)
-        flat = harness.ravel_workers(grads_w, dtype=grad_dtype)  # (W, d) view
-        x = harness.ravel(state.params)
+        with jax.named_scope("flat/ravel"):
+            flat = harness.ravel_workers(grads_w, dtype=grad_dtype)  # (W, d)
+            x = harness.ravel(state.params)
 
         grad_buf = state.grad_buf
         if stale_on:
@@ -250,35 +253,36 @@ def build_train_step(
             grad_buf = jnp.where(refresh[:, None], flat, grad_buf)
             flat = grad_buf
 
-        if adversary is None:
-            mask_k = byz_rank < cfg.n_byzantine
-        else:
-            mask_k = adversary.mask_at(byz_rank, k)
-        good_w = (~mask_k).astype(flat.dtype)[:, None]
-        honest_mean = (jnp.sum(flat * good_w, axis=0)
-                       / jnp.maximum(jnp.sum(good_w), 1.0))
-        v_ctx = (jnp.asarray(V, jnp.float32) if V > 0
-                 else _estimate_v(flat))   # flat is pre-attack: all honest
-        ctx = {
-            "true_grad": honest_mean, "V": v_ctx, "step": k,
-            "alive": state.prev_alive, "n_alive": state.prev_n_alive,
-            "prev_xi": state.prev_xi,
-        }
-        if adversary is None:
-            flat = attack_fn(key, flat, mask_k, ctx, **attack_kwargs)
-        else:
-            flat = adversary.attack(key, flat, mask_k, ctx, state.adv)
+        with jax.named_scope("adversary/attack"):
+            if adversary is None:
+                mask_k = byz_rank < cfg.n_byzantine
+            else:
+                mask_k = adversary.mask_at(byz_rank, k)
+            good_w = (~mask_k).astype(flat.dtype)[:, None]
+            honest_mean = (jnp.sum(flat * good_w, axis=0)
+                           / jnp.maximum(jnp.sum(good_w), 1.0))
+            v_ctx = (jnp.asarray(V, jnp.float32) if V > 0
+                     else _estimate_v(flat))   # flat is pre-attack: all honest
+            ctx = {
+                "true_grad": honest_mean, "V": v_ctx, "step": k,
+                "alive": state.prev_alive, "n_alive": state.prev_n_alive,
+                "prev_xi": state.prev_xi,
+            }
+            if adversary is None:
+                flat = attack_fn(key, flat, mask_k, ctx, **attack_kwargs)
+            else:
+                flat = adversary.attack(key, flat, mask_k, ctx, state.adv)
 
-        if part_on:
-            # reporting mask ≠ Byzantine mask: honest workers skip steps
-            # per p_report, Byzantine workers always deliver (worst case).
-            # fold_in leaves the attack's own key stream untouched, so
-            # armed machinery with p_report ≡ 1 stays on-trajectory.
-            pkey = jax.random.fold_in(key, 7919)
-            report = adversary.report_at(pkey, mask_k)
-            n_rep = jnp.sum(report).astype(jnp.int32)
-        else:
-            report = None
+            if part_on:
+                # reporting mask ≠ Byzantine mask: honest workers skip steps
+                # per p_report, Byzantine workers always deliver (worst case).
+                # fold_in leaves the attack's own key stream untouched, so
+                # armed machinery with p_report ≡ 1 stays on-trajectory.
+                pkey = jax.random.fold_in(key, 7919)
+                report = adversary.report_at(pkey, mask_k)
+                n_rep = jnp.sum(report).astype(jnp.int32)
+            else:
+                report = None
 
         if tel_on:
             guard, xi_flat, n_alive, alive, frame = agg_step(
@@ -290,15 +294,18 @@ def build_train_step(
             )
         adv = state.adv
         if adversary is not None:
-            adv = adversary.update_state(
-                state.adv, mask_k, flat, xi_flat, alive, n_alive, ctx
-            )
+            with jax.named_scope("adversary/attack"):
+                adv = adversary.update_state(
+                    state.adv, mask_k, flat, xi_flat, alive, n_alive, ctx
+                )
 
-        xi_tree = harness.unravel(xi_flat)
-        updates, opt_state = optimizer.update(
-            xi_tree, state.opt_state, state.params, k
-        )
-        params = tree_add(state.params, updates)
+        with jax.named_scope("flat/unravel"):
+            xi_tree = harness.unravel(xi_flat)
+        with jax.named_scope("optim/update"):
+            updates, opt_state = optimizer.update(
+                xi_tree, state.opt_state, state.params, k
+            )
+            params = tree_add(state.params, updates)
 
         ever_byz = state.ever_byz | mask_k
         good = (~mask_k).astype(jnp.float32)

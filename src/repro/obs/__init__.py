@@ -7,14 +7,14 @@ resync drift, adversary feedback) written into an on-device ring buffer;
 off-state is trace-identical to a build without this package.
 
 Host: :class:`EventLog` (structured JSONL + Perfetto/chrome-trace export),
-:func:`trace_span` / :func:`guard_scope` profiler spans, provenance meta,
-and the measured-vs-roofline comparator.  Rendered by
-``scripts/render_trace.py``.
+:func:`trace_span` profiler spans, the train step's named scopes
+(:data:`STEP_SCOPES`), provenance meta, and the measured-vs-roofline
+comparator.  Rendered by ``scripts/render_trace.py``.
 """
 from repro.obs.events import EventLog, write_chrome_trace
 from repro.obs.provenance import provenance_meta
 from repro.obs.roofline_compare import roofline_rows, spans_by_name
-from repro.obs.spans import guard_scope, trace_span
+from repro.obs.spans import STEP_SCOPES, trace_span
 from repro.obs.telemetry import (
     FRAME_SCHEMA,
     PER_WORKER_KEYS,
@@ -36,13 +36,13 @@ __all__ = [
     "FRAME_SCHEMA",
     "PER_WORKER_KEYS",
     "SCALAR_KEYS",
+    "STEP_SCOPES",
     "Telemetry",
     "TelemetryConfig",
     "TelemetryRing",
     "baseline_frame",
     "empty_frame",
     "guard_frame",
-    "guard_scope",
     "provenance_meta",
     "ring_init",
     "ring_push",

@@ -110,7 +110,11 @@ def write_chrome_trace(meta: dict, events: Iterable[dict], path: str) -> None:
     """Project (meta, events) onto the Chrome trace-event JSON format.
 
     * ``span`` events → complete slices (``ph: "X"``, µs timebase) on a
-      thread per span ``track`` (default: the span name's first segment);
+      thread per span ``track`` (default: the span name's first segment),
+      timed from the first span's ``t0_ns``, which the trace's metadata
+      keeps as ``span_origin_ns``: on the profiler's host clock, so a
+      slice sits at ``span_origin_ns + 1e3 * ts`` ns of a device trace's
+      clock (its ``profile_start_time`` plus an event's start);
     * ``guard_step`` events → counter tracks (``ph: "C"``) per run for the
       scalar series in ``_COUNTER_KEYS``, placed at ``step`` µs on a
       synthetic timebase (steps, not wall-clock — the filter timeline is
@@ -128,12 +132,9 @@ def write_chrome_trace(meta: dict, events: Iterable[dict], path: str) -> None:
          "args": {"name": label}}
         for label, pid in pids.items()
     ]
-    t0 = None
-    for ev in events:
-        kind = ev.get("type")
-        if kind == "span":
-            start = float(ev.get("t0", 0.0))
-            t0 = start if t0 is None else min(t0, start)
+    events = list(events)
+    origin = min((int(ev.get("t0_ns", 0)) for ev in events
+                  if ev.get("type") == "span"), default=0)
     for ev in events:
         kind = ev.get("type")
         if kind == "span":
@@ -143,10 +144,10 @@ def write_chrome_trace(meta: dict, events: Iterable[dict], path: str) -> None:
                 "ph": "X",
                 "pid": pids["spans"],
                 "tid": tid(track),
-                "ts": (float(ev.get("t0", 0.0)) - (t0 or 0.0)) * 1e6,
+                "ts": (int(ev.get("t0_ns", 0)) - origin) * 1e-3,
                 "dur": float(ev.get("dur_s", 0.0)) * 1e6,
                 "args": {k: v for k, v in ev.items()
-                         if k not in ("type", "name", "t0", "dur_s")},
+                         if k not in ("type", "name", "t0_ns", "dur_s")},
             })
         elif kind == "guard_step":
             run = ev.get("run", "run")
@@ -176,4 +177,5 @@ def write_chrome_trace(meta: dict, events: Iterable[dict], path: str) -> None:
                 "args": {k: v for k, v in ev.items() if k != "type"},
             })
     with open(path, "w") as f:
-        json.dump({"traceEvents": out, "metadata": meta}, f)
+        json.dump({"traceEvents": out,
+                   "metadata": {**meta, "span_origin_ns": origin}}, f)

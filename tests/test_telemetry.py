@@ -13,6 +13,7 @@ trainer's uniform metrics schema, and the JSONL/chrome-trace writers.
 import importlib.util
 import json
 import pathlib
+import time
 
 import jax
 import jax.numpy as jnp
@@ -300,6 +301,8 @@ class TestEventLog:
         log = EventLog(tool="test")
         with trace_span("train/chunk", log=log, lo=0, hi=4):
             pass
+        with trace_span("train/chunk", log=log, lo=4, hi=8):
+            pass
         log.guard_step({"step": 3.0, "n_alive": 7.0, "xi_norm": 0.5},
                        run="r")
         out = tmp_path / "t.json"
@@ -310,6 +313,37 @@ class TestEventLog:
         counter = next(ev for ev in trace["traceEvents"]
                        if ev["ph"] == "C" and "n_alive" in ev["name"])
         assert counter["ts"] == 3
+        # slices are timed in µs from the first span's t0_ns, which the
+        # metadata keeps: the export lays over the host clock exactly
+        first, second = log.events[:2]
+        slices = [ev for ev in trace["traceEvents"] if ev["ph"] == "X"]
+        assert trace["metadata"]["span_origin_ns"] == first["t0_ns"]
+        assert slices[0]["ts"] == 0
+        assert slices[1]["ts"] == (second["t0_ns"] - first["t0_ns"]) * 1e-3
+        assert slices[1]["args"] == {"lo": 4, "hi": 8}
+
+    def test_trace_span_t0_on_the_profilers_host_clock(self, tmp_path):
+        """A span's logged ``t0_ns`` is where the profiler puts its
+        annotation: the xplane's ``profile_start_time`` plus the event's
+        start, both on ``time.time_ns()``'s clock."""
+        from jax.profiler import ProfileData
+
+        log = EventLog(tool="test")
+        before = time.time_ns()
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            with trace_span("test/clock", log=log):
+                pass
+        finally:
+            jax.profiler.stop_trace()
+        span = log.events[0]
+        assert before <= span["t0_ns"] <= time.time_ns()
+        data = ProfileData.from_file(str(next(tmp_path.rglob("*.xplane.pb"))))
+        start_ns = next(dict(p.stats)["profile_start_time"]
+                        for p in data.planes if p.name == "Task Environment")
+        ann = next(e for p in data.planes for line in p.lines
+                   for e in line.events if e.name == "test/clock")
+        assert abs(start_ns + ann.start_ns - span["t0_ns"]) < 1e6   # 1 ms
 
     def test_trace_span_without_log(self):
         with trace_span("guard/filter"):
