@@ -9,7 +9,12 @@ caps activation residency at one layer — both essential for the
 512-device AOT dry-runs.
 
 The LM loss is computed in sequence chunks with vocab-sharded logits so the
-(B, S, 128k) logits tensor never materializes.
+(B, S, 128k) logits tensor never materializes, not even for the backward
+pass: each chunk is rematerialised (``jax.checkpoint``), so the backward
+keeps only the chunk's inputs (hidden states, labels, mask) and recomputes
+one chunk's logits at a time.  The gold logit is read with an ``iota == label``
+select, whose transpose is elementwise, rather than a gather, whose
+transpose is a scatter into a zeroed (B, c, V) buffer.
 """
 from __future__ import annotations
 
@@ -35,7 +40,9 @@ from repro.models.common import (
     rms_norm,
 )
 
-LOSS_CHUNK = 512  # sequence chunk for the vocab-sharded CE loss
+# sequence chunk for the vocab-sharded CE loss; the backward keeps each
+# chunk's inputs and recomputes its (B, LOSS_CHUNK, V) logits
+LOSS_CHUNK = 512
 
 
 # ---------------------------------------------------------------------------
@@ -210,13 +217,15 @@ def _chunked_ce(cfg: ModelConfig, params, h, labels, mask):
     lc = labels.reshape(B, n, c)
     mc = mask.reshape(B, n, c)
 
+    @jax.checkpoint
     def body(carry, xs):
         tot, cnt = carry
         hh, ll, mm = xs
         logits = _lm_head(cfg, params, hh)
         logits32 = logits.astype(jnp.float32)
         lse = jax.nn.logsumexp(logits32, axis=-1)
-        gold = jnp.take_along_axis(logits32, ll[..., None], axis=-1)[..., 0]
+        iota = jnp.arange(logits32.shape[-1], dtype=ll.dtype)
+        gold = jnp.where(iota == ll[..., None], logits32, 0.0).sum(-1)
         nll = (lse - gold) * mm
         return (tot + jnp.sum(nll), cnt + jnp.sum(mm)), None
 

@@ -10,6 +10,7 @@ import pytest
 from repro.configs import get_config
 from repro.configs.base import ModelConfig
 from repro.models import build_model
+from repro.models import model as model_lib
 from repro.models.attention import chunked_attention
 from repro.models.common import cross_entropy, rms_norm
 from repro.models.moe import moe_apply, moe_defs
@@ -198,6 +199,127 @@ def test_tied_embeddings_option(rng):
     batch = {"tokens": jnp.zeros((1, 32), jnp.int32), "labels": jnp.zeros((1, 32), jnp.int32)}
     loss, _ = jax.jit(model.loss_fn)(params, batch)
     assert jnp.isfinite(loss)
+
+
+def _gather_chunked_ce(cfg, params, h, labels, mask):
+    """The chunked CE as it was before rematerialisation: the gold logit by a
+    gather, the scan body not checkpointed.  Same chunking, same sums."""
+    B, S, D = h.shape
+    c = min(model_lib.LOSS_CHUNK, S)
+    n = S // c if S % c == 0 else 1
+    c = S // n
+
+    def body(carry, xs):
+        tot, cnt = carry
+        hh, ll, mm = xs
+        logits32 = model_lib._lm_head(cfg, params, hh).astype(jnp.float32)
+        lse = jax.nn.logsumexp(logits32, axis=-1)
+        gold = jnp.take_along_axis(logits32, ll[..., None], axis=-1)[..., 0]
+        return (tot + jnp.sum((lse - gold) * mm), cnt + jnp.sum(mm)), None
+
+    xs = tuple(jnp.moveaxis(a.reshape(B, n, c, *a.shape[2:]), 1, 0)
+               for a in (h, labels, mask))
+    (tot, cnt), _ = jax.lax.scan(body, (jnp.float32(0.0), jnp.float32(0.0)), xs)
+    return tot / jnp.maximum(cnt, 1.0)
+
+
+def _plain_ce(cfg, params, h, labels, mask):
+    """Unchunked f32 cross-entropy over the whole (B, S, V) logits."""
+    w = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
+    logits = jnp.einsum("bsd,dv->bsv", h.astype(jnp.float32), w.astype(jnp.float32),
+                        precision=jax.lax.Precision.HIGHEST)
+    nll = jax.nn.logsumexp(logits, axis=-1) - jnp.take_along_axis(
+        logits, labels[..., None], axis=-1)[..., 0]
+    return jnp.sum(nll * mask) / jnp.maximum(jnp.sum(mask), 1.0)
+
+
+class TestChunkedCE:
+    """``_chunked_ce``, the LM loss of every ``loss_fn``: its value and its
+    gradients with respect to the hidden states and the head (the embedding
+    when tied)."""
+
+    V, D, B = 1000, 64, 2
+
+    def _case(self, tied, S, masked, dtype):
+        cfg = dataclasses.replace(get_config("mamba2-130m").reduced(),
+                                  vocab_size=self.V, tie_embeddings=tied)
+        ks = jax.random.split(jax.random.PRNGKey(S + 2 * tied + masked), 4)
+        h = jax.random.normal(ks[0], (self.B, S, self.D)).astype(dtype)
+        w = (jax.random.normal(ks[1], (self.D, self.V)) * 0.3).astype(dtype)
+        params = {"embed": w.T} if tied else {"lm_head": w}
+        labels = jax.random.randint(ks[2], (self.B, S), 0, self.V)
+        mask = jnp.ones((self.B, S), jnp.float32)
+        if masked:
+            mask = (jax.random.uniform(ks[3], (self.B, S)) > 0.3).astype(jnp.float32)
+        return cfg, params, h, labels, mask
+
+    @staticmethod
+    def _value_and_grads(fn, cfg, params, h, labels, mask):
+        f = jax.jit(jax.value_and_grad(
+            lambda p, x: fn(cfg, p, x, labels, mask), argnums=(0, 1)))
+        return f(params, h)
+
+    @pytest.mark.parametrize("masked", [False, True], ids=["mask1", "mask0s"])
+    @pytest.mark.parametrize("S", [2048, 300], ids=["S2048_4chunks", "S300_1chunk"])
+    @pytest.mark.parametrize("tied", [False, True], ids=["untied", "tied"])
+    def test_matches_plain_f32_ce(self, tied, S, masked):
+        # bf16 parameters, as trained: bf16's tolerance against the plain f32 CE
+        cfg, params, h, labels, mask = self._case(tied, S, masked, jnp.bfloat16)
+        if masked:
+            assert 0 < float(mask.sum()) < mask.size
+        loss, (gp, gh) = self._value_and_grads(model_lib._chunked_ce, cfg, params,
+                                               h, labels, mask)
+        rloss, (rgp, rgh) = self._value_and_grads(_plain_ce, cfg, params, h, labels, mask)
+        np.testing.assert_allclose(float(loss), float(rloss), rtol=4e-3)
+        for got, want in [(gh, rgh), *zip(jax.tree_util.tree_leaves(gp),
+                                          jax.tree_util.tree_leaves(rgp))]:
+            assert got.dtype == want.dtype == jnp.bfloat16
+            got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+            rel = np.linalg.norm(got - want) / np.linalg.norm(want)
+            assert rel < 1e-2, rel
+
+        # f32 parameters: the gather-based formula's loss exactly, and its
+        # gradients to a few f32 ulps of their largest entry.  Bit equality
+        # does not hold for the gradients: the select's cotangent fuses into
+        # the softmax cotangent, where the CPU backend contracts the multiply
+        # and the add into one rounding (an FMA), and the scatter's did not.
+        cfg, params, h, labels, mask = self._case(tied, S, masked, jnp.float32)
+        (loss, grads) = self._value_and_grads(model_lib._chunked_ce, cfg, params,
+                                              h, labels, mask)
+        (rloss, rgrads) = self._value_and_grads(_gather_chunked_ce, cfg, params,
+                                                h, labels, mask)
+        np.testing.assert_array_equal(np.asarray(loss), np.asarray(rloss))
+        ulp = np.finfo(np.float32).eps
+        for a, b in zip(jax.tree_util.tree_leaves(grads), jax.tree_util.tree_leaves(rgrads)):
+            a, b = np.asarray(a), np.asarray(b)
+            np.testing.assert_allclose(a, b, rtol=0, atol=4 * ulp * np.abs(b).max())
+
+    def test_backward_keeps_no_logits_and_no_scatter(self):
+        """The trainer's form, ``jit(vmap(value_and_grad(loss_fn)))``: the
+        compiled program stacks no f32 (chunks, ..., V) logits for the
+        backward pass and scatters no gold-logit cotangent.  The one scatter
+        left is the token embedding's gradient, (W, V, D), the transpose of
+        the lookup ``embed[tokens]``."""
+        import re
+        W, S = 2, 2048
+        n_chunks = S // model_lib.LOSS_CHUNK
+        cfg = dataclasses.replace(get_config("mamba2-130m").reduced(), vocab_size=self.V)
+        assert n_chunks > 1 and self.V not in (cfg.d_model, S, n_chunks, W)
+        model = build_model(cfg)
+        params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+        tok = jax.ShapeDtypeStruct((W, 1, S), jnp.int32)
+        step = jax.jit(jax.vmap(jax.value_and_grad(model.loss_fn, has_aux=True),
+                                in_axes=(None, 0)))
+        hlo = step.lower(params, {"tokens": tok, "labels": tok}).compile().as_text()
+
+        def dims(shape):
+            return tuple(int(x) for x in shape.split(",") if x)
+
+        scattered = {dims(m) for m in re.findall(r"= \w+\[([\d,]*)\]\{[^}]*\} scatter\(", hlo)}
+        assert scattered <= {(W, self.V, cfg.d_model)}, scattered
+        stacked = {d for d in map(dims, re.findall(r"f32\[([\d,]*)\]", hlo))
+                   if n_chunks in d and self.V in d}
+        assert not stacked, stacked
 
 
 class TestQuantKVCache:
