@@ -4,6 +4,9 @@ Chunked SSD algorithm for train/prefill (quadratic *within* length-Q chunks,
 linear recurrence *across* chunks → O(S·Q) work, O(1) state), and the exact
 O(1)-per-token recurrence for decode. This is what makes ``long_500k``
 native for mamba2/jamba: decode state is (H, N, P) regardless of context.
+B and C are (…, G, N), shared by the R = H // G heads of each group; the
+chunked scan splits the heads as (G, R) and contracts each head against
+its group's B and C, so no per-head copy of them is made.
 
 Projection layout: we split the fused in_proj of the reference CUDA
 implementation into separate z/x/B/C/dt projections and give x, B, C their
@@ -64,7 +67,11 @@ def _ssd_scan(
     chunk: int,
     initial_state: jax.Array | None = None,   # (B, H, N, P)
 ) -> tuple[jax.Array, jax.Array]:
-    """Chunked SSD. Returns (y (B,S,H,P), final_state (B,H,N,P))."""
+    """Chunked SSD. Returns (y (B,S,H,P), final_state (B,H,N,P)).
+
+    B and C stay per group: the heads are split group-major as (G, R),
+    R = H // G, so head h reads group h // R, and every contraction with
+    B or C runs against the (…, G, N) tensors, never a per-head copy."""
     Bsz, S, H, P = xh.shape
     G, N = Bm.shape[2], Bm.shape[3]
     R = H // G
@@ -73,62 +80,58 @@ def _ssd_scan(
     nc = S // Q
 
     f32 = jnp.float32
-    xc = xh.reshape(Bsz, nc, Q, H, P).astype(f32)
-    dtc = dt.reshape(Bsz, nc, Q, H).astype(f32)
+    xc = xh.reshape(Bsz, nc, Q, G, R, P).astype(f32)
+    dtc = dt.reshape(Bsz, nc, Q, G, R).astype(f32)
     Bc = Bm.reshape(Bsz, nc, Q, G, N).astype(f32)
     Cc = Cm.reshape(Bsz, nc, Q, G, N).astype(f32)
 
-    dtx = dtc[..., None] * xc                                  # (B,nc,Q,H,P)
-    log_a = A.astype(f32) * dtc                                # negative, (B,nc,Q,H)
+    dtx = dtc[..., None] * xc                                  # (B,nc,Q,G,R,P)
+    log_a = A.reshape(G, R).astype(f32) * dtc                  # negative, (B,nc,Q,G,R)
     cum = jnp.cumsum(log_a, axis=2)                            # inclusive cumsum
-    cum_last = cum[:, :, -1]                                   # (B,nc,H)
+    cum_last = cum[:, :, -1]                                   # (B,nc,G,R)
 
     # ---- intra-chunk (quadratic within Q) ----
     s = jnp.einsum("bcign,bcjgn->bcgij", Cc, Bc)               # (B,nc,G,Q,Q)
-    s = jnp.repeat(s, R, axis=2)                               # (B,nc,H,Q,Q)
-    decay = cum[:, :, :, None, :] - cum[:, :, None, :, :]      # cum_i - cum_j (B,nc,Q,Q,H)
-    decay = jnp.moveaxis(decay, -1, 2)                         # (B,nc,H,Q,Q)
+    cum_t = jnp.moveaxis(cum, 2, -1)                           # (B,nc,G,R,Q)
+    decay = cum_t[..., :, None] - cum_t[..., None, :]          # cum_i - cum_j (B,nc,G,R,Q,Q)
     iq = jnp.arange(Q)
-    causal = (iq[:, None] >= iq[None, :])[None, None, None]
+    causal = iq[:, None] >= iq[None, :]
     # mask *before* the exp: above the diagonal cum_i − cum_j > 0 grows with
     # the chunk length and overflows f32 at Q = 256, and masking the
     # product afterwards still sends 0·inf = NaN back through exp's gradient
-    M = s * jnp.exp(jnp.where(causal, decay, -jnp.inf))
-    y_intra = jnp.einsum("bchij,bcjhp->bcihp", M, dtx)
+    M = s[:, :, :, None] * jnp.exp(jnp.where(causal, decay, -jnp.inf))
+    y_intra = jnp.einsum("bcgrij,bcjgrp->bcigrp", M, dtx)
 
     # ---- per-chunk outgoing state ----
-    w_end = jnp.exp(cum_last[:, :, None, :] - cum)             # decay to chunk end (B,nc,Q,H)
-    # state contribution: sum_j w_end_j * B_j ⊗ dtx_j → (B,nc,H,N,P)
-    Bfull = jnp.repeat(Bc, R, axis=3)                          # (B,nc,Q,H,N)
-    chunk_states = jnp.einsum("bcjhn,bcjhp,bcjh->bchnp", Bfull, dtx, w_end)
+    w_end = jnp.exp(cum_last[:, :, None] - cum)                # decay to chunk end (B,nc,Q,G,R)
+    # state contribution: sum_j B_j ⊗ (w_end_j · dtx_j) → (B,nc,G,R,N,P)
+    chunk_states = jnp.einsum("bcjgn,bcjgrp->bcgrnp", Bc, dtx * w_end[..., None])
 
     # ---- inter-chunk recurrence (sequential scan over nc chunks) ----
     state0 = (
-        initial_state.astype(f32)
+        initial_state.reshape(Bsz, G, R, N, P).astype(f32)
         if initial_state is not None
-        else jnp.zeros((Bsz, H, N, P), f32)
+        else jnp.zeros((Bsz, G, R, N, P), f32)
     )
-    Cfull = jnp.repeat(Cc, R, axis=3)                          # (B,nc,Q,H,N)
 
     def body(state, inp):
-        c_full, cum_c, cum_last_c, cs = inp
+        c, cum_c, cum_last_c, cs = inp
         # y_inter[i] = exp(cum_i) · C_i · state_prev
-        w_in = jnp.exp(cum_c)                                  # (B,Q,H)
-        y_int = jnp.einsum("bqhn,bhnp,bqh->bqhp", c_full, state, w_in)
+        y_int = jnp.einsum("bqgn,bgrnp->bqgrp", c, state) * jnp.exp(cum_c)[..., None]
         state_new = jnp.exp(cum_last_c)[..., None, None] * state + cs
         return state_new, y_int
 
     xs = (
-        jnp.moveaxis(Cfull, 1, 0),        # (nc, B, Q, H, N)
-        jnp.moveaxis(cum, 1, 0),          # (nc, B, Q, H)
-        jnp.moveaxis(cum_last, 1, 0),     # (nc, B, H)
-        jnp.moveaxis(chunk_states, 1, 0),  # (nc, B, H, N, P)
+        jnp.moveaxis(Cc, 1, 0),            # (nc, B, Q, G, N)
+        jnp.moveaxis(cum, 1, 0),           # (nc, B, Q, G, R)
+        jnp.moveaxis(cum_last, 1, 0),      # (nc, B, G, R)
+        jnp.moveaxis(chunk_states, 1, 0),  # (nc, B, G, R, N, P)
     )
     final_state, y_inter = jax.lax.scan(body, state0, xs)
-    y_inter = jnp.moveaxis(y_inter, 0, 1).reshape(Bsz, nc, Q, H, P)
+    y_inter = jnp.moveaxis(y_inter, 0, 1)                      # (B,nc,Q,G,R,P)
 
     y = (y_intra + y_inter).reshape(Bsz, S, H, P)
-    return y.astype(xh.dtype), final_state
+    return y.astype(xh.dtype), final_state.reshape(Bsz, H, N, P)
 
 
 class MambaCache(NamedTuple):

@@ -1,6 +1,7 @@
 """Model-layer unit tests: attention equivalences, SSD vs naive recurrence,
 MoE dispatch, decode-vs-forward agreement."""
 import dataclasses
+import re
 
 import jax
 import jax.numpy as jnp
@@ -73,10 +74,53 @@ class TestChunkedAttention:
         np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-4)
 
 
+def _ssd_scan_per_head(xh, dt, A, Bm, Cm, chunk, initial_state=None):
+    """The chunked SSD with B, C and the C·B scores copied to every head
+    (``jnp.repeat``): the formulation ``_ssd_scan`` replaced, kept as an
+    oracle of the same mathematics summed in another order."""
+    Bsz, S, H, P = xh.shape
+    G, N = Bm.shape[2], Bm.shape[3]
+    R = H // G
+    Q = min(chunk, S)
+    nc = S // Q
+    xc = xh.reshape(Bsz, nc, Q, H, P)
+    dtc = dt.reshape(Bsz, nc, Q, H)
+    Bc = Bm.reshape(Bsz, nc, Q, G, N)
+    Cc = Cm.reshape(Bsz, nc, Q, G, N)
+    dtx = dtc[..., None] * xc
+    cum = jnp.cumsum(A * dtc, axis=2)
+    cum_last = cum[:, :, -1]
+    s = jnp.repeat(jnp.einsum("bcign,bcjgn->bcgij", Cc, Bc), R, axis=2)
+    decay = jnp.moveaxis(cum[:, :, :, None, :] - cum[:, :, None, :, :], -1, 2)
+    iq = jnp.arange(Q)
+    causal = (iq[:, None] >= iq[None, :])[None, None, None]
+    M = s * jnp.exp(jnp.where(causal, decay, -jnp.inf))
+    y_intra = jnp.einsum("bchij,bcjhp->bcihp", M, dtx)
+    w_end = jnp.exp(cum_last[:, :, None, :] - cum)
+    Bfull = jnp.repeat(Bc, R, axis=3)
+    chunk_states = jnp.einsum("bcjhn,bcjhp,bcjh->bchnp", Bfull, dtx, w_end)
+    Cfull = jnp.repeat(Cc, R, axis=3)
+    state0 = (jnp.zeros((Bsz, H, N, P)) if initial_state is None else initial_state)
+
+    def body(state, inp):
+        c_full, cum_c, cum_last_c, cs = inp
+        y_int = jnp.einsum("bqhn,bhnp,bqh->bqhp", c_full, state, jnp.exp(cum_c))
+        return jnp.exp(cum_last_c)[..., None, None] * state + cs, y_int
+
+    xs = tuple(jnp.moveaxis(a, 1, 0) for a in (Cfull, cum, cum_last, chunk_states))
+    final_state, y_inter = jax.lax.scan(body, state0, xs)
+    y_inter = jnp.moveaxis(y_inter, 0, 1)
+    return (y_intra + y_inter).reshape(Bsz, S, H, P), final_state
+
+
+HEAD_GROUPS = [(4, 1), (4, 2), (4, 4)]
+
+
 class TestSSD:
-    def test_matches_naive_recurrence(self, rng):
+    @pytest.mark.parametrize("H,G", HEAD_GROUPS)
+    def test_matches_naive_recurrence(self, rng, H, G):
         """Chunked SSD == exact sequential state-space recurrence."""
-        B, S, H, P, G, N = 2, 64, 4, 8, 1, 16
+        B, S, P, N = 2, 64, 8, 16
         ks = jax.random.split(rng, 4)
         x = jax.random.normal(ks[0], (B, S, H, P))
         dt = jax.nn.softplus(jax.random.normal(ks[1], (B, S, H)))
@@ -101,6 +145,52 @@ class TestSSD:
         np.testing.assert_allclose(y_chunk, y_naive, rtol=2e-3, atol=2e-3)
         np.testing.assert_allclose(state_chunk, h, rtol=2e-3, atol=2e-3)
 
+    @pytest.mark.parametrize("G", [1, 2])
+    def test_matches_per_head_formulation(self, rng, G):
+        """Contracting each head against its group gives what copying B, C
+        and the scores to every head gave: y, the final state and the
+        gradients of every input, from a carried initial state."""
+        B, S, H, P, N = 2, 64, 4, 8, 16
+        ks = jax.random.split(rng, 6)
+        args = (
+            jax.random.normal(ks[0], (B, S, H, P)),
+            jax.nn.softplus(jax.random.normal(ks[1], (B, S, H))),
+            -jnp.exp(jax.random.normal(ks[2], (H,)) * 0.3),
+            jax.random.normal(ks[3], (B, S, G, N)) * 0.5,
+            jax.random.normal(ks[4], (B, S, G, N)) * 0.5,
+        )
+        s0 = jax.random.normal(ks[5], (B, H, N, P)) * 0.3
+
+        def run(scan):
+            def loss(*a):
+                y, st = scan(*a, 16, initial_state=s0)
+                return jnp.sum(jnp.sin(y)) + jnp.sum(st ** 2)
+            return (jax.jit(scan, static_argnums=5)(*args, 16, initial_state=s0),
+                    jax.jit(jax.grad(loss, argnums=range(5)))(*args))
+
+        (y, st), grads = run(_ssd_scan)
+        (y_ref, st_ref), grads_ref = run(_ssd_scan_per_head)
+        # f32 sums taken in another order: relative to each array's largest entry
+        for got, want in [(y, y_ref), (st, st_ref), *zip(grads, grads_ref)]:
+            np.testing.assert_allclose(got, want, rtol=1e-4,
+                                       atol=1e-4 * float(jnp.max(jnp.abs(want))))
+
+    def test_no_per_head_copy_of_B_or_C(self):
+        """The lowered forward and backward hold no f32 array with the head
+        axis beside the state axis: a per-head copy of B or C."""
+        B, S, H, G, P, N, Q = 2, 32, 6, 1, 4, 10, 8
+        shapes = [jax.ShapeDtypeStruct(s, jnp.float32) for s in
+                  [(B, S, H, P), (B, S, H), (H,), (B, S, G, N), (B, S, G, N)]]
+
+        def loss(*a):
+            y, st = _ssd_scan(*a, Q)
+            return jnp.sum(y ** 2) + jnp.sum(st)
+
+        text = jax.jit(jax.value_and_grad(loss, argnums=range(5))).lower(*shapes).as_text()
+        assert "x6x4x10xf32" in text or "x6x10x4xf32" in text   # the state is per head
+        copies = re.findall(rf"tensor<[0-9x]*x{H}x{N}x(?:{Q}x)?f32>", text)
+        assert not copies, copies
+
     def test_initial_state_continuation(self, rng):
         """Running two halves with carried state == one full pass."""
         B, S, H, P, G, N = 1, 64, 2, 4, 1, 8
@@ -117,15 +207,16 @@ class TestSSD:
         np.testing.assert_allclose(jnp.concatenate([y1, y2], 1), y_full, rtol=2e-3, atol=2e-3)
         np.testing.assert_allclose(s2, s_full, rtol=2e-3, atol=2e-3)
 
-    def test_gradient_finite_at_long_chunk(self, rng):
+    @pytest.mark.parametrize("H,G", HEAD_GROUPS)
+    def test_gradient_finite_at_long_chunk(self, rng, H, G):
         """At a published chunk length (256) the decay between the ends of
         a chunk overflows f32 above the diagonal; the gradient must stay
         finite, and the forward must equal the same scan at a short chunk."""
-        B, S, H, P, G, N = 1, 256, 2, 4, 1, 8
+        B, S, P, N = 1, 256, 4, 8
         ks = jax.random.split(rng, 5)
         x = jax.random.normal(ks[0], (B, S, H, P))
         dt = jnp.full((B, S, H), 0.5)
-        A = jnp.array([-1.0, -8.0])   # Σ A·dt over the chunk ≈ −1024 ≪ −88
+        A = jnp.array([-1.0, -8.0, -2.0, -4.0])   # Σ A·dt over the chunk ≈ −1024 ≪ −88
         Bm = jax.random.normal(ks[3], (B, S, G, N)) * 0.5
         Cm = jax.random.normal(ks[4], (B, S, G, N)) * 0.5
 
